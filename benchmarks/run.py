@@ -113,12 +113,15 @@ def main() -> None:
         print(f"\n# ==== {modname} ====")
         try:
             importlib.import_module(modname).main()
-        except Exception as e:  # noqa: BLE001 — keep the suite running
+        except Exception as e:  # noqa: BLE001 — run the rest, then fail
+            failed.append(modname)
             print(f"# {modname} FAILED: {type(e).__name__}: {e}")
             traceback.print_exc()
-    if args.dry:
-        sys.exit(1 if failed else 0)
-    print_roofline_summary()
+    if not args.dry:
+        print_roofline_summary()
+    if failed:
+        print(f"# {len(failed)} module(s) failed: {', '.join(failed)}")
+    sys.exit(1 if failed else 0)
 
 
 if __name__ == "__main__":

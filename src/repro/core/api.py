@@ -16,7 +16,6 @@ from typing import Any, Optional, Sequence
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from .ensemble import EnsembleResult, solve_ensemble_local
 from .interp import data_flatten, data_unflatten
@@ -157,13 +156,13 @@ def solve_ensemble(eprob: EnsembleProblem, mesh: Optional[Mesh] = None,
                             naccept=nacc, nreject=nrej)
 
     count_spec = spec if per_traj_counts else P()
-    fn = shard_map(local, mesh=mesh,
-                   in_specs=(spec, spec) + (P(),) * len(dleaves),
-                   out_specs=EnsembleResult(
-                       ts=P(), us=spec, u_final=spec, t_final=spec,
-                       naccept=count_spec, nreject=count_spec, nf=P(),
-                       status=P(), njac=P(), nfact=P()),
-                   check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh,
+                       in_specs=(spec, spec) + (P(),) * len(dleaves),
+                       out_specs=EnsembleResult(
+                           ts=P(), us=spec, u_final=spec, t_final=spec,
+                           naccept=count_spec, nreject=count_spec, nf=P(),
+                           status=P(), njac=P(), nfact=P()),
+                       check_vma=False)
     if kw.get("sensitivity") is not None:
         # the bounded adjoint loop wraps segments in jax.checkpoint, which
         # lowers to closed_call — shard_map cannot evaluate that eagerly
@@ -238,6 +237,6 @@ def ensemble_moments(us: Array, mesh: Optional[Mesh] = None,
         var = jnp.maximum(s2c / n, 0)
         return mean, var
 
-    fn = shard_map(local, mesh=mesh, in_specs=(spec,),
-                   out_specs=(P(), P()), check_rep=False)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec,),
+                       out_specs=(P(), P()), check_vma=False)
     return fn(us)

@@ -453,13 +453,10 @@ def resolve_auto(eprob: EnsembleProblem, spec: MethodSpec, *, t0=None,
                                         backend=_c.backend,
                                         lane_tile=_c.lane_tile,
                                         **tune_kw).u_final
-        try:
-            stat = measure(jax.jit(run), u0s_t, ps_t, repeats=k)
-        except Exception:   # a candidate that fails to run can never win
-            continue
+        # a candidate that raises is a bug (on a chip: a kernel the compiler
+        # refused), never a candidate that merely lost — let it surface
+        stat = measure(jax.jit(run), u0s_t, ps_t, repeats=k)
         timings.append((c, stat["median"]))
-    if not timings:
-        return Decision(*DEFAULT_STRATEGY, source="default", key=ckey)
     winner, _ = min(timings, key=lambda ct: ct[1])
 
     # 6. persist
@@ -482,16 +479,15 @@ def broadcast_decision(dec: Decision) -> Decision:
     runs return the decision unchanged."""
     if jax.process_count() == 1:
         return dec
-    try:
-        from jax.experimental import multihost_utils
-        from .methods import BACKENDS, STRATEGIES
-        payload = jnp.asarray([STRATEGIES.index(dec.strategy),
-                               BACKENDS.index(dec.backend),
-                               -1 if dec.lane_tile is None
-                               else int(dec.lane_tile)], jnp.int32)
-        got = np.asarray(multihost_utils.broadcast_one_to_all(payload))
-        return Decision(STRATEGIES[int(got[0])], BACKENDS[int(got[1])],
-                        None if int(got[2]) < 0 else int(got[2]),
-                        source=dec.source, key=dec.key)
-    except Exception:
-        return dec
+    # no fallback to the local decision: hosts that disagree would trace
+    # different programs, so a failed broadcast must fail the solve
+    from jax.experimental import multihost_utils
+    from .methods import BACKENDS, STRATEGIES
+    payload = jnp.asarray([STRATEGIES.index(dec.strategy),
+                           BACKENDS.index(dec.backend),
+                           -1 if dec.lane_tile is None
+                           else int(dec.lane_tile)], jnp.int32)
+    got = np.asarray(multihost_utils.broadcast_one_to_all(payload))
+    return Decision(STRATEGIES[int(got[0])], BACKENDS[int(got[1])],
+                    None if int(got[2]) < 0 else int(got[2]),
+                    source=dec.source, key=dec.key)

@@ -3,7 +3,9 @@
 GPUs give hardware linear interpolation + boundary handling on uniform grids via
 texture units. TPUs have no texture hardware; the native equivalents are:
 
-  mode="gather"  — index computation + jnp.take (general; XLA gather).
+  mode="gather"  — index computation + a masked sum over the K nodes
+                   (`_take1d`; exact, and lowered by Mosaic, which has no
+                   1-D gather).  2-D tables still use jnp.take.
   mode="onehot"  — interpolation weights as a (…, K) one-hot-pair matrix
                    contracted with the table: a matmul, i.e. MXU work. Inside a
                    Pallas kernel the table is VMEM-resident (BlockSpec broadcast
@@ -93,13 +95,27 @@ def _catmull_rom_weights(w):
             0.5 * (w3 - w2))
 
 
+def _take1d(values, i):
+    """values[i] for a (K,) table and integer indices i of any shape.
+
+    A masked sum over the K nodes instead of a gather: the TPU kernel
+    compiler (Mosaic) lowers no 1-D gather, and the fused kernels hold the
+    table in VMEM.  Exactly one term of the sum is nonzero, so the result
+    equals ``jnp.take(values, i)`` bitwise."""
+    K = values.shape[0]
+    node = jax.lax.broadcasted_iota(jnp.int32, (K,) + jnp.shape(i), 0)
+    vals = values.reshape((K,) + (1,) * jnp.ndim(i))
+    return jnp.sum(jnp.where(node == i, vals, jnp.zeros((), values.dtype)),
+                   axis=0)
+
+
 def interp1d(table: UniformTable1D, x, mode: str = "gather"):
     """Interpolation at x (any shape). Clamped boundaries, all modes."""
     K = table.K
     i, w = _locate(x, table.x0, table.dx, K)
     if mode == "gather":
-        v0 = jnp.take(table.values, i)
-        v1 = jnp.take(table.values, i + 1)
+        v0 = _take1d(table.values, i)
+        v1 = _take1d(table.values, i + 1)
         return v0 * (1.0 - w) + v1 * w
     if mode == "onehot":
         # weights (…, K): (1-w) at i, w at i+1 — contraction is a matmul (MXU)
@@ -120,7 +136,7 @@ def interp1d(table: UniformTable1D, x, mode: str = "gather"):
         out = None
         for off, wk in zip((-1, 0, 1, 2), ws):
             idx = jnp.clip(i + off, 0, K - 1)
-            term = wk * jnp.take(table.values, idx)
+            term = wk * _take1d(table.values, idx)
             out = term if out is None else out + term
         return out
     raise ValueError(f"unknown mode {mode!r} (one of {MODES})")

@@ -41,6 +41,29 @@ def default_checkpoint_every(bounded_steps: int) -> int:
     return max(1, math.isqrt(max(1, int(bounded_steps))))
 
 
+def bool_codec(template: Carry):
+    """(encode, decode) between a carry and the same carry with its bool
+    leaves held as int32.
+
+    The TPU kernel compiler (Mosaic) cannot carry boolean vectors through a
+    loop or a cond (it fails to legalize the ``scf.yield``), and the engines
+    carry per-lane ``done``/``fresh`` masks.  Loops that run inside the Pallas
+    kernels therefore carry them as 0/1 int32; the round trip is exact.
+    """
+    is_bool = jax.tree_util.tree_map(
+        lambda x: jnp.result_type(x) == jnp.bool_, template)
+
+    def enc(c):
+        return jax.tree_util.tree_map(
+            lambda b, x: x.astype(jnp.int32) if b else x, is_bool, c)
+
+    def dec(c):
+        return jax.tree_util.tree_map(lambda b, x: x != 0 if b else x,
+                                      is_bool, c)
+
+    return enc, dec
+
+
 def solver_loop(cond: Callable[[Carry], Any], body: Callable[[Carry], Carry],
                 carry0: Carry, *, bounded_steps: Optional[int] = None,
                 checkpoint_every: Optional[int] = None) -> Carry:
@@ -59,7 +82,10 @@ def solver_loop(cond: Callable[[Carry], Any], body: Callable[[Carry], Carry],
     ``status == 1``).
     """
     if bounded_steps is None:
-        return jax.lax.while_loop(cond, body, carry0)
+        enc, dec = bool_codec(carry0)
+        out = jax.lax.while_loop(lambda c: cond(dec(c)),
+                                 lambda c: enc(body(dec(c))), enc(carry0))
+        return dec(out)
     bounded = int(bounded_steps)
     if bounded <= 0:
         raise ValueError(f"bounded_steps must be positive, got {bounded}")

@@ -47,14 +47,24 @@ from .tableaus import ROS23W, RosenbrockTableau
 
 
 def _jac_lanes(f, u, p, t, jac=None):
-    """Per-lane Jacobian: u (n, B) -> J (B, n, n).
+    """Per-lane Jacobian: u (n, B) -> J (n, n, B), lanes last.
 
     Analytic hook: component-style `jac(u, p, t)` broadcasts over the lane
-    axis and returns (n, n, B); AD fallback is vmap(jacfwd)."""
+    axis and returns (n, n, B).  AD fallback: one jvp per state component,
+    with the unit tangent e_j in every lane (the lanes never interact), so
+    column j is ∂f/∂u_j for all lanes at once.  No lanes-first array and no
+    transpose: the layout the fused kernel can hold.  The tangents are
+    stacked constant rows: one built from an iota compare makes the TPU
+    kernel compiler (Mosaic) abort on the row reads inside f."""
     if jac is not None:
-        return jnp.moveaxis(jac(u, p, t), -1, 0)
-    t_ax = 0 if jnp.ndim(t) else None
-    return jax.vmap(jax.jacfwd(f), in_axes=(-1, -1, t_ax))(u, p, t)
+        return jac(u, p, t)
+    n = u.shape[0]
+    cols = []
+    for j in range(n):
+        e = jnp.stack([jnp.full(u.shape[1:], float(i == j), u.dtype)
+                       for i in range(n)])
+        cols.append(jax.jvp(lambda uu: f(uu, p, t), (u,), (e,))[1])
+    return jnp.stack(cols, axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -67,29 +77,33 @@ def _jac_lanes(f, u, p, t, jac=None):
 
 def _w_build(J, dt, gam, lanes, dtype):
     """W = I − γ·dt·J, same expressions as the eager step (bitwise-stable)."""
-    n = J.shape[-1]
+    n = J.shape[0]
     if lanes:
-        eye = jnp.eye(n, dtype=dtype)[None]
-        gdt = (dt * gam)[:, None, None] if jnp.ndim(dt) else dt * gam
-        return eye - gdt * J                               # (B, n, n)
+        eye = jnp.eye(n, dtype=dtype)[..., None]
+        gdt = (dt * gam)[None, None] if jnp.ndim(dt) else dt * gam
+        return eye - gdt * J                               # (n, n, B)
     return jnp.eye(n, dtype=dtype) - dt * gam * J          # (n, n)
 
 
 def _w_factor(W, mode, lanes):
     """Mode-specific factorization -> carry-able pytree.
 
-    "jnp"/scalar: LAPACK (lu, piv); "lanes": the pivoted lanes-LU kernel body
+    W is (n, n) in scalar mode and (n, n, B) in lanes mode.  "jnp"/scalar:
+    LAPACK (lu, piv), batch-first; "lanes": the pivoted lanes-LU kernel body
     (rows/swaps/mults/pivmin lists — a pytree); "pallas": the factorization
     cannot persist across a `pallas_call` boundary, so the carried state is W
-    itself and each resolve launches the batched kernel (J reuse still saves
-    the expensive jac/jacfwd passes; `nfact` then counts W rebuilds)."""
-    if not lanes or mode in ("jnp", None):
+    itself, batch-first, and each resolve launches the batched kernel (J
+    reuse still saves the expensive jac/jacfwd passes; `nfact` then counts W
+    rebuilds)."""
+    if not lanes:
         return jax.scipy.linalg.lu_factor(W)
+    if mode in ("jnp", None):
+        return jax.scipy.linalg.lu_factor(jnp.moveaxis(W, -1, 0))
     if mode == "lanes":
         from repro.kernels.lu.kernel import lu_factor_lanes
-        return lu_factor_lanes(jnp.moveaxis(W, 0, -1))
+        return lu_factor_lanes(W)
     if mode == "pallas":
-        return W
+        return jnp.moveaxis(W, -1, 0)
     raise ValueError(f"unknown linsolve mode {mode!r}")
 
 
@@ -118,12 +132,12 @@ def _secant_update(J, du, dF, gain, mask, lanes):
     Skipped where Δu = 0 or the correction is non-finite."""
     if lanes:
         nn = jnp.sum(du * du, axis=0)                      # (B,)
-        Jdu = jnp.sum(J * du.T[:, None, :], axis=-1).T     # (n, B)
+        Jdu = jnp.sum(J * du[None], axis=1)                # (n, B)
         r = dF - Jdu
-        corr = (r.T[:, :, None] * du.T[:, None, :]
-                / jnp.where(nn > 0, nn, 1.0)[:, None, None])   # (B, n, n)
+        corr = (r[:, None] * du[None]
+                / jnp.where(nn > 0, nn, 1.0)[None, None])  # (n, n, B)
         ok = (mask & (nn > 0)
-              & jnp.all(jnp.isfinite(corr), axis=(1, 2)))[:, None, None]
+              & jnp.all(jnp.isfinite(corr), axis=(0, 1)))[None, None]
     else:
         nn = jnp.sum(du * du)
         corr = (jnp.outer(dF - J @ du, du)
@@ -166,7 +180,7 @@ def rosenbrock_step(f, rtab: RosenbrockTableau, u, p, t, dt, *, lanes=False,
     dtype = u.dtype
     gam = rtab.gamma
     if lanes:
-        J = _jac_lanes(f, u, p, t, jac)                 # (B, n, n)
+        J = _jac_lanes(f, u, p, t, jac)                 # (n, n, B)
     else:
         J = (jac(u, p, t) if jac is not None
              else jax.jacfwd(lambda uu: f(uu, p, t))(u))  # (n, n)
@@ -320,7 +334,7 @@ def solve_rosenbrock(f, rtab: RosenbrockTableau, u0, p, t0, tf, dt0, *,
     saveat = jnp.asarray(saveat, dtype)
     S = saveat.shape[0]
     us0 = jnp.zeros((S,) + u0.shape, dtype)
-    pre = (saveat <= t0).reshape((S,) + (1,) * u0.ndim)
+    pre = (saveat[:, None] <= t0).reshape((S,) + (1,) * u0.ndim)  # see solvers
     us0 = jnp.where(pre, u0[None], us0)
 
     gam = rtab.gamma
@@ -418,7 +432,7 @@ def solve_rosenbrock(f, rtab: RosenbrockTableau, u0, p, t0, tf, dt0, *,
                 J_old, fac_old, dtf_old = state
                 J_new = jax.lax.cond(any_lane(need_jac),
                                      lambda: jac_eval(u, t), lambda: J_old)
-                jmask = (need_jac[:, None, None] if lanes else need_jac)
+                jmask = (need_jac[None, None] if lanes else need_jac)
                 J_sel = jnp.where(jmask, J_new, J_old)
                 fac_new = _w_factor(_w_build(J_sel, dt_step, gam, lanes,
                                              dtype), linsolve, lanes)

@@ -237,27 +237,36 @@ def sde_save_grid(t0, dt, n_steps: int, save_every: int, dtype):
         * jnp.arange(1, n_steps // save_every + 1, dtype=dtype)
 
 
-def _sde_snapshot(us, u, k, save_every: int):
-    """Masked snapshot write for step k (shared by the fixed-dt loop bodies)."""
+def _sde_snapshot(us, u, k, save_every: int, select: bool = False):
+    """Masked snapshot write for step k (shared by the fixed-dt loop bodies).
+
+    ``select=True`` writes through an iota mask over the save axis instead of
+    a dynamic_update_slice, which the TPU kernel compiler (Mosaic) does not
+    lower; the Pallas body uses it.  Same values, O(S) work per snapshot."""
     s = (k + 1) // save_every - 1
-    return jax.lax.cond(
-        (k + 1) % save_every == 0,
-        lambda us: jax.lax.dynamic_update_slice(
-            us, u[None], (s,) + (0,) * (us.ndim - 1)),
-        lambda us: us, us)
+
+    def write(us):
+        if select:
+            hit = jax.lax.broadcasted_iota(jnp.int32, us.shape, 0) == s
+            return jnp.where(hit, u[None], us)
+        return jax.lax.dynamic_update_slice(us, u[None],
+                                            (s,) + (0,) * (us.ndim - 1))
+
+    return jax.lax.cond((k + 1) % save_every == 0, write, lambda us: us, us)
 
 
 def sde_step_and_save(stepper, f, g, noise: str, u, us, p, t0, dt, k, z,
-                      save_every: int):
+                      save_every: int, select: bool = False):
     """ONE fixed-dt step + masked snapshot write — the loop body every SDE
     execution path shares (vmap, XLA lanes, Pallas kernel), so the
     (step, save-index) plumbing that bitwise cross-backend parity depends on
     exists exactly once.  Layout-polymorphic: u (n,)/(n, B) with us
-    (S, n)/(S, n, B); z is the N(0,1) draw for step k."""
+    (S, n)/(S, n, B); z is the N(0,1) draw for step k.  ``select``: see
+    `_sde_snapshot`."""
     dtv = jnp.asarray(dt, u.dtype)
     t = t0 + k * dtv
     u = stepper(f, g, u, p, t, dtv, z * jnp.sqrt(dtv), noise)
-    us = _sde_snapshot(us, u, k, save_every)
+    us = _sde_snapshot(us, u, k, save_every, select)
     return u, us
 
 
@@ -272,7 +281,8 @@ def sde_event_state0(cshape, t0, dtype):
 
 
 def sde_step_save_event(stepper, f, g, noise: str, ev: Event, u, us, estate,
-                        p, t0, dt, k, z, save_every: int):
+                        p, t0, dt, k, z, save_every: int,
+                        select: bool = False):
     """Event-aware variant of `sde_step_and_save` — the shared fixed-dt loop
     body with per-lane termination (paper §6.6 on the SDE family).
 
@@ -300,7 +310,7 @@ def sde_step_save_event(stepper, f, g, noise: str, ev: Event, u, us, estate,
     # terminal: report the located event time; otherwise the grid time
     t_out = jnp.where(term, t_next, jnp.where(active, t + dtv,
                                               estate["t_out"]))
-    us = _sde_snapshot(us, u, k, save_every)
+    us = _sde_snapshot(us, u, k, save_every, select)
     estate = dict(done=estate["done"] | term, t_out=t_out,
                   naccept=estate["naccept"] + active.astype(jnp.int32),
                   event_t=ev_t, event_count=ev_n)

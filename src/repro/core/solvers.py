@@ -250,6 +250,12 @@ def _grid_save(f, tab, us, saveat, u_old, u_new, ks, p, t_old, dt_step,
         return jnp.where(cross_e, vals, us)
 
 
+def _nf_per_attempt(tab: Tableau, event) -> int:
+    """RHS evaluations per attempted step: FSAL reuses the last stage,
+    except after an event may have modified the state."""
+    return tab.stages - 1 if (tab.fsal and event is None) else tab.stages
+
+
 def _make_adaptive_body(f, tab: Tableau, opts: AdaptiveOptions, ctrl, event,
                         lanes: bool, dtype, cshape, axes, saveat, save_grid,
                         bounded, p=None, tf=None):
@@ -330,10 +336,8 @@ def _make_adaptive_body(f, tab: Tableau, opts: AdaptiveOptions, ctrl, event,
         # FSAL: reuse last stage; recompute after an event modified the state
         if tab.fsal and event is None:
             k1_new = jnp.where(acc_e, ks[-1], k1)
-            nf_inc = jnp.where(active, tab.stages - 1, 0)
         else:
             k1_new = jnp.where(acc_e, f(u_new, p_, t_new), k1)
-            nf_inc = jnp.where(active, tab.stages, 0)
 
         # ---- dense save -----------------------------------------------------
         if save_grid:
@@ -363,10 +367,15 @@ def _make_adaptive_body(f, tab: Tableau, opts: AdaptiveOptions, ctrl, event,
             enorm_prev=enorm_prev, done=done,
             naccept=c["naccept"] + accept.astype(jnp.int32),
             nreject=c["nreject"] + (active & ~accept).astype(jnp.int32),
-            nf=c["nf"] + nf_inc.astype(jnp.int32),
             status=statusv, iters=c["iters"] + 1,
             event_t=ev_t, event_count=ev_n,
         )
+        if "nf" in c:
+            # resume carries count RHS work as they go; solve_adaptive
+            # derives nf from the attempt counters after the loop instead
+            # (a carry fed only by `done` is a loop Mosaic cannot lay out)
+            out["nf"] = c["nf"] + jnp.where(
+                active, _nf_per_attempt(tab, event), 0).astype(jnp.int32)
         if us is not None:
             out["us"] = us
         if per_lane_consts:
@@ -405,7 +414,9 @@ def solve_adaptive(f, tab: Tableau, u0, p, t0, tf, dt0,
     save_grid = opts.save == "grid"
     us0 = jnp.zeros((S,) + u0.shape, dtype)
     # prefill save points at/before t0 with u0
-    pre = (saveat <= t0).reshape((S,) + (1,) * u0.ndim)
+    # compare as an (S, 1) column: Mosaic cannot reshape an (S,) vector to
+    # rank 3, but it can extend a column with unit dims
+    pre = (saveat[:, None] <= t0).reshape((S,) + (1,) * u0.ndim)
     us0 = jnp.where(pre, u0[None], us0)
 
     k0 = f(u0, p, tv)
@@ -416,7 +427,6 @@ def solve_adaptive(f, tab: Tableau, u0, p, t0, tf, dt0,
         us=us0,
         naccept=jnp.zeros(cshape, jnp.int32),
         nreject=jnp.zeros(cshape, jnp.int32),
-        nf=jnp.ones(cshape, jnp.int32),
         status=jnp.zeros(cshape, jnp.int32),
         iters=jnp.asarray(0, jnp.int32),
         event_t=jnp.full(cshape, jnp.inf, dtype),
@@ -436,7 +446,9 @@ def solve_adaptive(f, tab: Tableau, u0, p, t0, tf, dt0,
                        jnp.where(out["done"], 0, 1)).astype(jnp.int32)
     res = SolveResult(ts=saveat, us=out["us"], t_final=out["t"],
                       u_final=out["u"], naccept=out["naccept"],
-                      nreject=out["nreject"], status=status, nf=out["nf"])
+                      nreject=out["nreject"], status=status,
+                      nf=1 + _nf_per_attempt(tab, event)
+                      * (out["naccept"] + out["nreject"]))
     if event is not None:
         return res, dict(event_t=out["event_t"], event_count=out["event_count"])
     return res

@@ -40,8 +40,13 @@ Array = Any
 # VMEM-aware lane-tile selection (paper §5.2 occupancy formula)
 # ---------------------------------------------------------------------------
 
-# ~16 MB VMEM/core on current TPUs; budget half of it for the kernel's
-# loop-carried state + output block, leaving headroom for pipelining/spills.
+# 16 MiB: the scoped-VMEM limit Mosaic enforces on a v5e (its
+# RESOURCE_EXHAUSTED error reports "limit 16.00M").  Budget half of it for
+# the kernel's loop-carried state + output block, leaving headroom for
+# pipelining/spills.  The per-lane formula below undercounts what Mosaic
+# allocates by about 3x (docs/kernels.md): the small-n phases stay inside
+# the limit because the 4096-lane cap binds first, but a tile that the
+# budget itself limits can still exceed it.
 VMEM_BYTES_PER_CORE = 16 * 1024 * 1024
 DEFAULT_VMEM_BUDGET = VMEM_BYTES_PER_CORE // 2
 
@@ -170,7 +175,8 @@ class KernelContext(NamedTuple):
 
 # extras are (kind, array) with kind:
 #   "broadcast" — (K,) array identical for every tile (e.g. the saveat grid)
-#   "lanes"     — (..., N) array tiled over the trajectory axis (noise tables)
+#   "lanes"     — (..., N) array tiled over the trajectory axis (noise tables);
+#                 the body receives its VMEM ref, not a loaded value
 #   "table"     — any-rank array identical for every tile (dataset table
 #                 values: `prob.data` leaves).  Broadcast like "broadcast"
 #                 but rank-preserving: the leaf rides its own BlockSpec into
@@ -230,13 +236,16 @@ def run_ensemble_kernel(body: Callable, u0s: Array, ps: Array, *, ts: Array,
             in_specs.append(pl.BlockSpec((1, K), lambda i: (0, 0)))
             unwrap.append(lambda v: v[0])
         elif kind == "lanes":
+            # handed to the body as the VMEM ref itself: the body reads rows
+            # at dynamic indices (ref[k]), which Mosaic lowers and a value
+            # dynamic_slice it does not
             padded, _ = pad_lanes(jnp.asarray(arr), B)
             args.append(padded)
             blk = padded.shape[:-1] + (B,)
             nd = padded.ndim
             in_specs.append(pl.BlockSpec(
                 blk, lambda i, _nd=nd: (0,) * (_nd - 1) + (i,)))
-            unwrap.append(lambda v: v)
+            unwrap.append(None)
         elif kind == "table":
             # dataset leaf: flatten to one VMEM row broadcast to every grid
             # cell, restore the natural shape inside the kernel
@@ -269,7 +278,8 @@ def run_ensemble_kernel(body: Callable, u0s: Array, ps: Array, *, ts: Array,
     def kernel(*refs):
         u0 = refs[0][...]
         p = refs[1][...]
-        ex = tuple(fn(r[...]) for fn, r in zip(unwrap, refs[2:n_in]))
+        ex = tuple(r if fn is None else fn(r[...])
+                   for fn, r in zip(unwrap, refs[2:n_in]))
         us_ref, uf_ref, tfin_ref, stats_ref = refs[n_in:]
         ctx = KernelContext(tile=pl.program_id(0), lane_tile=B, n_state=n,
                             n_param=m, n_save=S)
@@ -565,8 +575,7 @@ def sde_body(f, g, stepper, noise: str, *, t0: float, dt: float,
 
         def noise_fn(k):
             if use_table:
-                return jax.lax.dynamic_slice(
-                    table, (k, 0, 0), (1, m_noise, B))[0].astype(dtype)
+                return table[k].astype(dtype)   # dynamic row of the VMEM ref
             return counter_normals_threefry(seed, k, lane, rows, dtype)
 
         us0 = jnp.zeros((S, ctx.n_state, B), dtype)
@@ -575,7 +584,8 @@ def sde_body(f, g, stepper, noise: str, *, t0: float, dt: float,
             def step(k, carry):
                 u, us = carry
                 return sde_step_and_save(stepper, f_, g_, noise, u, us, p, t0,
-                                         dt, k, noise_fn(k), save_every)
+                                         dt, k, noise_fn(k), save_every,
+                                         select=True)
 
             u_f, us = jax.lax.fori_loop(0, n_steps, step, (u0, us0))
             t_final = jnp.full((B,), t0 + n_steps * dt, dtype)
@@ -585,7 +595,8 @@ def sde_body(f, g, stepper, noise: str, *, t0: float, dt: float,
                 u, us, estate = carry
                 return sde_step_save_event(stepper, f_, g_, noise, event, u,
                                            us, estate, p, t0, dt, k,
-                                           noise_fn(k), save_every)
+                                           noise_fn(k), save_every,
+                                           select=True)
 
             estate0 = sde_event_state0((B,), t0, dtype)
             u_f, us, estate = jax.lax.fori_loop(0, n_steps, step,

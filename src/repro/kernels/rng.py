@@ -41,9 +41,18 @@ def threefry2x32(k0, k1, c0, c1):
     return x0, x1
 
 
+def _u32_to_f32(bits):
+    """uint32 -> float32, correctly rounded, without a uint32->float convert
+    (Mosaic has none).  Both 16-bit halves convert exactly and the one f32
+    add rounds their exact sum once, so this equals ``bits.astype(f32)``."""
+    hi = (bits >> jnp.uint32(16)).astype(jnp.int32).astype(jnp.float32)
+    lo = (bits & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    return hi * jnp.float32(65536.0) + lo
+
+
 def _to_unit(bits):
     """uint32 -> float in (0, 1): (bits + 0.5) / 2^32, exact in f32 range."""
-    return (bits.astype(jnp.float32) + 0.5) * jnp.float32(2.0 ** -32)
+    return (_u32_to_f32(bits) + 0.5) * jnp.float32(2.0 ** -32)
 
 
 def bridge_normals(seed, node, lane_idx, row_idx, dtype=jnp.float32):

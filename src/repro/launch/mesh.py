@@ -18,6 +18,11 @@ def make_production_mesh(*, multi_pod: bool = False):
 
 
 def make_local_mesh():
-    """Whatever devices exist, as a 1D 'data' mesh (CPU tests/examples)."""
+    """Whatever devices exist, as a 1D 'data' mesh (CPU tests/examples).
+
+    Auto axis type: the ensemble path shards through `jax.shard_map`, and
+    arrays typed with an Explicit mesh axis (`jax.make_mesh`'s default)
+    cannot then be moved to or compared against a single device."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    return jax.make_mesh((n,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))

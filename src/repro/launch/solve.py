@@ -20,6 +20,7 @@ from repro.core import EnsembleProblem
 from repro.core.api import ensemble_moments, solve_ensemble
 from repro.core.sde import solve_sde_ensemble
 from repro.dist.fault import WorkQueue
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 
 
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--mesh", default="none", choices=["none", "local"])
     ap.add_argument("--work-queue", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     t0 = time.perf_counter()
     if args.problem == "lorenz":

@@ -1,6 +1,7 @@
 """Autotuned dispatch (`ensemble="auto"`, repro.core.autotune): key schema,
 profile-cache round-trips, capability pruning, bitwise parity with explicit
-dispatch, and the graceful static fallback when timing is unavailable.
+dispatch, the graceful static fallback when timing is unavailable, and no
+fallback at all when a candidate fails to run.
 
 The CI bench-smoke job runs exactly this module as its autotune leg: every
 test tunes into a pytest tmpdir cache (never ~/.cache), and the round-trip
@@ -265,6 +266,25 @@ def test_disabled_env_falls_back_to_static_default(cache, monkeypatch,
     assert int(r.status) == 0
 
 
+def test_failing_candidate_raises_instead_of_vanishing(cache, monkeypatch):
+    """A candidate that raises (on a chip: a kernel the compiler refused)
+    fails the resolve; it must not drop out of the ranking in silence."""
+    real = at.measure
+    calls = {"n": 0}
+
+    def broken_second(fn, *a, **k):
+        calls["n"] += 1
+        if calls["n"] == 2:
+            raise RuntimeError("kernel refused")
+        return real(fn, *a, **k)
+
+    monkeypatch.setattr(at, "measure", broken_second)
+    with pytest.raises(RuntimeError, match="kernel refused"):
+        at.resolve_auto(lorenz_ensemble(32), get_method("tsit5"),
+                        cache_path=cache, **SOLVE_KW)
+    assert not os.path.exists(cache)           # nothing half-tuned persisted
+
+
 def test_cold_cache_under_jit_falls_back(cache, monkeypatch,
                                          counted_measure):
     monkeypatch.setenv(at.CACHE_ENV, cache)
@@ -342,3 +362,46 @@ def test_concurrent_writers_merge_not_last_wins(tmp_path):
     # a fresh in-process load (cold memory layer) sees the union too
     at.clear_memory_cache()
     assert set(at._load_entries(path)) == {"cfgA", "cfgB"}
+
+
+# ---------------------------------------------------------------------------
+# persistent compile cache placement (chip entry points)
+# ---------------------------------------------------------------------------
+
+_CACHE_SCRIPT = r"""
+import os, sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+from repro.launch.compile_cache import use_compile_cache
+path = use_compile_cache(root=sys.argv[1])
+jax.jit(lambda x: x * 2 + 1)(jnp.arange(4.0)).block_until_ready()
+print(path)
+print(os.environ["REPRO_AUTOTUNE_CACHE"])
+"""
+
+
+@pytest.mark.parametrize("from_env", [False, True])
+def test_compile_cache_lands_in_env_dir_or_checkout(tmp_path, from_env):
+    """`use_compile_cache` keeps the cache where JAX_COMPILATION_CACHE_DIR
+    says, else in `.jax_cache/` under the checkout root; the autotune
+    profile cache follows it.  Run in a child (CPU only) because the cache
+    directory is process-global JAX state."""
+    import subprocess
+    import sys
+
+    root = tmp_path / "checkout"
+    env_dir = tmp_path / "env_cache"
+    src = os.path.join(os.path.dirname(at.__file__), "..", "..")
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", at.CACHE_ENV)}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=os.path.abspath(src))
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    out = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT, str(root)],
+                         env=env, capture_output=True, text=True,
+                         timeout=300, check=True).stdout.split()
+    want = env_dir if from_env else root / ".jax_cache"
+    assert out == [str(want), str(want / "autotune.json")]
+    assert any(want.iterdir())                 # compiled entries written
+    if from_env:
+        assert not (root / ".jax_cache").exists()

@@ -348,25 +348,23 @@ def test_lu_singular_system_falls_back_to_jnp_reference():
     W = jnp.asarray(np.stack([W_sing, W_ok]))
     b = jnp.asarray(rng.normal(size=(2, 3)))
     # the raw kernel flags the singular lane (pivmin not > 0: zero or NaN
-    # once a zero pivot poisons later rows) and emits a garbage column that
-    # DIFFERS from the jnp reference (±inf vs LAPACK's all-NaN) ...
+    # once a zero pivot poisons later rows) and emits a garbage column ...
     x_raw, pivmin = lu_solve_pallas(jnp.moveaxis(W, 0, -1), b.T, lane_tile=2)
     assert not bool(pivmin[0] > 0) and bool(pivmin[1] > 0)
     assert np.any(np.isinf(np.asarray(x_raw)[:, 0]))
     x = np.asarray(batched_solve(W, b))
     ref = np.asarray(ref_solve(W, b))
-    # ... so the fallback is observable: batched_solve returns the jnp
-    # reference's pattern for the singular lane, not the kernel's
+    # ... and batched_solve returns exactly the jnp reference's answer for
+    # the singular lane (whatever garbage pattern the installed LAPACK
+    # gives: all-NaN on some versions, a NaN/±inf mix on others)
     np.testing.assert_array_equal(x[0], ref[0])
-    assert not np.any(np.isinf(x[0]))
     # and the healthy lane is untouched by the fallback
     np.testing.assert_allclose(x[1], ref[1], rtol=1e-12)
     # the zero matrix (pivmin NaN-poisoned at step 0) is also caught: the
-    # ops layer may not return the kernel's raw garbage for it
+    # ops layer returns the reference's answer, not the kernel's raw garbage
     W0 = jnp.asarray(np.stack([np.zeros((3, 3)), W_ok]))
     x0 = np.asarray(batched_solve(W0, b))
     np.testing.assert_array_equal(x0[0], np.asarray(ref_solve(W0, b))[0])
-    assert not np.any(np.isinf(x0[0]))
 
 
 def test_lu_auto_lane_tile_shares_vmem_formula():

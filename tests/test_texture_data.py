@@ -301,8 +301,16 @@ def test_resolve_auto_key_distinguishes_data(tmp_path):
     dec_data = resolve_auto(ep, spec, dt0=1e-2,
                             saveat=jnp.linspace(0.0, 5.0, 6),
                             cache_path=cache, repeats=1)
+    # the data-free twin computes its force in closed form: a callback that
+    # closes over the table array would hand the Pallas candidates a
+    # captured constant, which the kernel refuses (and a refused candidate
+    # fails the resolve instead of dropping out of it)
+    def free_rhs(u, p, t):
+        force = jnp.sin(1.3 * t) + 0.5 * jnp.cos(0.4 * t)
+        return jnp.stack([u[1], -p[0] * u[0] - p[1] * u[1] + force])
+
     free = EnsembleProblem(
-        dataclasses.replace(bind_problem_data(prob), name="free"),
+        dataclasses.replace(prob, f=free_rhs, data=None, name="free"),
         4, u0s=ep.materialize()[0], ps=ep.materialize()[1])
     dec_free = resolve_auto(free, spec, dt0=1e-2,
                             saveat=jnp.linspace(0.0, 5.0, 6),
