@@ -134,13 +134,15 @@ def config_key(spec: MethodSpec, *, n: int, N: int, dtype, adaptive: bool,
                events: bool, w_reuse: bool, error_est: str,
                device: Optional[str] = None,
                sensitivity: Optional[str] = None,
-               data_sig: str = "none") -> str:
+               data_sig: str = "none", consts: bool = False) -> str:
     """Deterministic cache key — a readable ``k=v|...`` string (field order
     fixed), hashable across processes and debuggable in the JSON by eye.
     ``data_sig`` is the dataset-shape signature
     (`repro.core.interp.data_signature`): VMEM-resident tables shift the
     kernel crossovers (and the auto lane_tile), so a data-driven solve must
-    not reuse the data-free profile of the same method."""
+    not reuse the data-free profile of the same method.  ``consts`` marks
+    a problem whose callbacks close over arrays: it has no Pallas
+    candidate, so its profile is not that of the closure-free twin."""
     return "|".join((
         f"method={spec.name}",
         f"n={int(n)}",
@@ -152,6 +154,7 @@ def config_key(spec: MethodSpec, *, n: int, N: int, dtype, adaptive: bool,
         f"error_est={error_est}",
         f"sens={sensitivity or 'none'}",
         f"data={data_sig}",
+        f"consts={bool(consts)}",
         f"device={device_kind() if device is None else device}"))
 
 
@@ -341,6 +344,27 @@ def _is_traced(*vals) -> bool:
                for leaf in jax.tree_util.tree_leaves(vals))
 
 
+def _captures_constants(prob, event, u0s, ps) -> bool:
+    """True when a callback the Pallas kernel would trace closes over an
+    array (a jaxpr constant) — e.g. a `bind_problem_data` closure or an RHS
+    that captures a coefficient array.  `pallas_call` refuses captured
+    constants (arrays enter a kernel only as explicit inputs, which is what
+    ``prob.data`` is for), so such a problem has no Pallas candidate.
+    Traced on abstract values, so it also answers under jit."""
+    u = jax.ShapeDtypeStruct(u0s.shape[1:], u0s.dtype)
+    p = jax.ShapeDtypeStruct(ps.shape[1:], ps.dtype)
+    t = jax.ShapeDtypeStruct((), u0s.dtype)
+    data = getattr(prob, "data", None)
+    args = (u, p, t) if data is None else (u, p, t, jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), jnp.result_type(x)),
+        data))
+    fns = [(getattr(prob, name, None), args) for name in ("f", "g", "jac")]
+    if event is not None:
+        fns += [(event.condition, (u, p, t)), (event.affect, (u, p, t))]
+    return any(jax.make_jaxpr(fn)(*a).consts for fn, a in fns
+               if fn is not None)
+
+
 def _tuning_slice(u0s, ps, N: int):
     """Evenly-strided subsample of the real ensemble (parameter sweeps are
     usually ordered; a head slice would tune on an unrepresentative corner)."""
@@ -372,10 +396,11 @@ def resolve_auto(eprob: EnsembleProblem, spec: MethodSpec, *, t0=None,
                                     w_reuse=w_reuse, error_est=error_est,
                                     event=event)
     pdata = getattr(prob, "data", None)
+    consts = _captures_constants(prob, event, u0s, ps)
     ckey = config_key(spec, n=n, N=N, dtype=u0s.dtype, adaptive=ad,
                       events=ev, w_reuse=wr, error_est=ee,
                       sensitivity=sensitivity,
-                      data_sig=data_signature(pdata))
+                      data_sig=data_signature(pdata), consts=consts)
     path = cache_path or default_cache_path()
 
     # 1. cache (works under jit too: the key is static shape/dtype data).
@@ -408,6 +433,7 @@ def resolve_auto(eprob: EnsembleProblem, spec: MethodSpec, *, t0=None,
     except (TypeError, jax.errors.TracerIntegerConversionError,
             jax.errors.ConcretizationTypeError):
         concrete_seed, allow_pallas = 0, spec.family != "sde"
+    allow_pallas = allow_pallas and not consts
     cands = candidates(spec, n=n, m=m, n_save=S_real, N=min(N, TUNE_MAX_N),
                        dtype=u0s.dtype, adaptive=ad, events=ev, w_reuse=wr,
                        error_est=ee, allow_pallas=allow_pallas,

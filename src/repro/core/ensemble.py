@@ -60,7 +60,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .controller import PIController
-from .interp import data_flatten, data_unflatten, data_words
+from .interp import (data_flatten, data_unflatten, data_words,
+                     kernel_lookups)
 from .methods import MethodSpec, get_method
 from .problem import (EnsembleProblem, ODEProblem, SDEProblem,
                       bind_problem_data)
@@ -1188,55 +1189,61 @@ def solve_ensemble_local(eprob: EnsembleProblem, alg="tsit5",
                     "compute initial_dt outside jit or use backend='xla'")
         auto_dt_nf = 2 * u0s.shape[0]
 
-    if spec.family == "sde":
-        if not isinstance(prob, SDEProblem):
+    # the kernel family's XLA twin traces table lookups the way the Pallas
+    # body must (masked sums, no 1-D gather), so the two backends stay
+    # bitwise twins; vmap and array keep the O(1) gather
+    with kernel_lookups(ensemble == "kernel"):
+        if spec.family == "sde":
+            if not isinstance(prob, SDEProblem):
+                raise TypeError(
+                    f"method {spec.name!r} is an SDE stepper but the "
+                    f"problem is {type(prob).__name__}")
+            return _solve_sde(spec, prob, u0s, ps, ensemble=ensemble,
+                              backend=backend, t0=t0, tf=tf, dt0=dt0,
+                              saveat=saveat, n_steps=n_steps,
+                              save_every=save_every, lane_tile=lane_tile,
+                              key=key, seed=seed, noise_table=noise_table,
+                              event=event,
+                              adaptive=adaptive, rtol=rtol, atol=atol,
+                              max_iters=max_iters, lane_offset=lane_offset,
+                              brownian_depth=brownian_depth,
+                              error_est=error_est,
+                              sensitivity=sensitivity,
+                              adjoint_steps=adjoint_steps,
+                              checkpoint_every=checkpoint_every,
+                              raw_prob=raw_prob)
+
+        if error_est is not None:
+            raise ValueError(
+                "error_est selects the adaptive SDE error estimator; "
+                f"{spec.name!r} ({spec.family}) embeds via its tableau")
+
+        if isinstance(prob, SDEProblem):
             raise TypeError(
-                f"method {spec.name!r} is an SDE stepper but the problem is "
-                f"{type(prob).__name__}")
-        return _solve_sde(spec, prob, u0s, ps, ensemble=ensemble,
-                          backend=backend, t0=t0, tf=tf, dt0=dt0,
-                          saveat=saveat, n_steps=n_steps,
-                          save_every=save_every, lane_tile=lane_tile, key=key,
-                          seed=seed, noise_table=noise_table, event=event,
-                          adaptive=adaptive, rtol=rtol, atol=atol,
-                          max_iters=max_iters, lane_offset=lane_offset,
-                          brownian_depth=brownian_depth, error_est=error_est,
-                          sensitivity=sensitivity,
-                          adjoint_steps=adjoint_steps,
-                          checkpoint_every=checkpoint_every,
-                          raw_prob=raw_prob)
+                f"problem {prob.name!r} is stochastic; pick an sde method "
+                f"(e.g. alg='em'), not {spec.name!r}")
 
-    if error_est is not None:
-        raise ValueError(
-            "error_est selects the adaptive SDE error estimator; "
-            f"{spec.name!r} ({spec.family}) embeds via its tableau")
-
-    if isinstance(prob, SDEProblem):
-        raise TypeError(
-            f"problem {prob.name!r} is stochastic; pick an sde method "
-            f"(e.g. alg='em'), not {spec.name!r}")
-
-    if spec.family == "rosenbrock":
-        res = _solve_rosenbrock(spec, prob, u0s, ps, ensemble=ensemble,
-                                backend=backend, t0=t0, tf=tf, dt0=dt0,
-                                saveat=saveat, rtol=rtol, atol=atol,
-                                lane_tile=lane_tile, max_iters=max_iters,
-                                linsolve=linsolve, event=event,
-                                w_reuse=w_reuse, sensitivity=sensitivity,
-                                adjoint_steps=adjoint_steps,
-                                checkpoint_every=checkpoint_every,
-                                raw_prob=raw_prob)
-    else:
-        res = _solve_erk(spec, prob, u0s, ps, ensemble=ensemble,
-                         backend=backend, t0=t0, tf=tf, dt0=dt0,
-                         saveat=saveat, rtol=rtol, atol=atol,
-                         adaptive=adaptive, n_steps=n_steps,
-                         save_every=save_every, lane_tile=lane_tile,
-                         max_iters=max_iters, event=event,
-                         sensitivity=sensitivity,
-                         adjoint_steps=adjoint_steps,
-                         checkpoint_every=checkpoint_every,
-                         raw_prob=raw_prob)
+        if spec.family == "rosenbrock":
+            res = _solve_rosenbrock(spec, prob, u0s, ps, ensemble=ensemble,
+                                    backend=backend, t0=t0, tf=tf, dt0=dt0,
+                                    saveat=saveat, rtol=rtol, atol=atol,
+                                    lane_tile=lane_tile, max_iters=max_iters,
+                                    linsolve=linsolve, event=event,
+                                    w_reuse=w_reuse, sensitivity=sensitivity,
+                                    adjoint_steps=adjoint_steps,
+                                    checkpoint_every=checkpoint_every,
+                                    raw_prob=raw_prob)
+        else:
+            res = _solve_erk(spec, prob, u0s, ps, ensemble=ensemble,
+                             backend=backend, t0=t0, tf=tf, dt0=dt0,
+                             saveat=saveat, rtol=rtol, atol=atol,
+                             adaptive=adaptive, n_steps=n_steps,
+                             save_every=save_every, lane_tile=lane_tile,
+                             max_iters=max_iters, event=event,
+                             sensitivity=sensitivity,
+                             adjoint_steps=adjoint_steps,
+                             checkpoint_every=checkpoint_every,
+                             raw_prob=raw_prob)
     if auto_dt_nf:
         res = res._replace(nf=res.nf + auto_dt_nf)
     return res

@@ -3,9 +3,11 @@
 GPUs give hardware linear interpolation + boundary handling on uniform grids via
 texture units. TPUs have no texture hardware; the native equivalents are:
 
-  mode="gather"  — index computation + a masked sum over the K nodes
-                   (`_take1d`; exact, and lowered by Mosaic, which has no
-                   1-D gather).  2-D tables still use jnp.take.
+  mode="gather"  — index computation + jnp.take (general; XLA gather).
+                   Inside a Pallas kernel body, where Mosaic lowers no 1-D
+                   gather, and in the kernel family's XLA twin, a 1-D
+                   lookup becomes a masked sum over the K nodes instead
+                   (`kernel_lookups`; bitwise the same value).
   mode="onehot"  — interpolation weights as a (…, K) one-hot-pair matrix
                    contracted with the table: a matmul, i.e. MXU work. Inside a
                    Pallas kernel the table is VMEM-resident (BlockSpec broadcast
@@ -30,6 +32,8 @@ into the fused Pallas kernels as real BlockSpec arguments
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 from typing import Any, Tuple
 
@@ -95,13 +99,32 @@ def _catmull_rom_weights(w):
             0.5 * (w3 - w2))
 
 
+_IN_KERNEL = contextvars.ContextVar("repro_interp_in_kernel", default=False)
+
+
+@contextlib.contextmanager
+def kernel_lookups(enabled: bool = True):
+    """Trace 1-D table lookups as masked sums while a Pallas kernel body
+    (or the kernel family's XLA twin) is being traced.  A jitted callback
+    first traced outside this context keeps its gather inside it."""
+    token = _IN_KERNEL.set(bool(enabled))
+    try:
+        yield
+    finally:
+        _IN_KERNEL.reset(token)
+
+
 def _take1d(values, i):
     """values[i] for a (K,) table and integer indices i of any shape.
 
-    A masked sum over the K nodes instead of a gather: the TPU kernel
-    compiler (Mosaic) lowers no 1-D gather, and the fused kernels hold the
-    table in VMEM.  Exactly one term of the sum is nonzero, so the result
-    equals ``jnp.take(values, i)`` bitwise."""
+    An XLA gather, except under `kernel_lookups`: the TPU kernel compiler
+    (Mosaic) lowers no 1-D gather, and the fused kernels hold the table in
+    VMEM, so there the lookup is a masked sum over the K nodes.  Exactly
+    one term of the sum is nonzero, so both equal ``jnp.take(values, i)``
+    bitwise; the sum costs O(K) per lookup, which is why it stays with the
+    kernel family."""
+    if not _IN_KERNEL.get():
+        return jnp.take(values, i)
     K = values.shape[0]
     node = jax.lax.broadcasted_iota(jnp.int32, (K,) + jnp.shape(i), 0)
     vals = values.reshape((K,) + (1,) * jnp.ndim(i))

@@ -34,6 +34,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from repro.core.interp import kernel_lookups
+
 Array = Any
 
 # ---------------------------------------------------------------------------
@@ -283,7 +285,8 @@ def run_ensemble_kernel(body: Callable, u0s: Array, ps: Array, *, ts: Array,
         us_ref, uf_ref, tfin_ref, stats_ref = refs[n_in:]
         ctx = KernelContext(tile=pl.program_id(0), lane_tile=B, n_state=n,
                             n_param=m, n_save=S)
-        us, uf, t_final, stats = body(ctx, u0, p, ex)
+        with kernel_lookups():
+            us, uf, t_final, stats = body(ctx, u0, p, ex)
         us_ref[...] = us                  # (S, n, B): one HBM flush
         uf_ref[...] = uf
         tfin_ref[...] = t_final[None]

@@ -285,6 +285,36 @@ def test_failing_candidate_raises_instead_of_vanishing(cache, monkeypatch):
     assert not os.path.exists(cache)           # nothing half-tuned persisted
 
 
+def test_captured_array_prunes_pallas_candidates(cache, monkeypatch):
+    """An RHS that closes over an array hands pallas_call a captured
+    constant, which it refuses: such a problem tunes over the XLA
+    candidates only (pruned, not raised), and ``"auto"`` still solves it."""
+    import dataclasses
+    ep = lorenz_ensemble(32)
+    u0s, ps = ep.materialize()
+    scale = jnp.ones((3,), u0s.dtype)
+
+    def f_captured(u, p, t):
+        return ep.prob.f(u, p, t) * scale.reshape((3,) + (1,) * (u.ndim - 1))
+
+    cap = EnsembleProblem(dataclasses.replace(ep.prob, f=f_captured), 32,
+                          u0s=u0s, ps=ps)
+    spec = get_method("tsit5")
+    monkeypatch.setenv(at.CACHE_ENV, cache)
+    plain = at.resolve_auto(ep, spec, **SOLVE_KW)
+    dec = at.resolve_auto(cap, spec, **SOLVE_KW)
+    # a separate profile: the closure-free twin's Pallas winner is never
+    # handed to the problem the kernel refuses
+    assert "consts=False" in plain.key and "consts=True" in dec.key
+    assert plain.source == dec.source == "tuned"
+    assert any("pallas" in label for label, _ in plain.timings)
+    assert dec.timings and not any("pallas" in label
+                                   for label, _ in dec.timings)
+    # the front door dispatches the cached (XLA) winner and solves
+    r = solve_ensemble_local(cap, alg="tsit5", ensemble="auto", **SOLVE_KW)
+    assert int(r.status) == 0
+
+
 def test_cold_cache_under_jit_falls_back(cache, monkeypatch,
                                          counted_measure):
     monkeypatch.setenv(at.CACHE_ENV, cache)

@@ -364,7 +364,14 @@ def test_lu_singular_system_falls_back_to_jnp_reference():
     # ops layer returns the reference's answer, not the kernel's raw garbage
     W0 = jnp.asarray(np.stack([np.zeros((3, 3)), W_ok]))
     x0 = np.asarray(batched_solve(W0, b))
-    np.testing.assert_array_equal(x0[0], np.asarray(ref_solve(W0, b))[0])
+    ref0 = np.asarray(ref_solve(W0, b))
+    np.testing.assert_array_equal(x0[0], ref0[0])
+    # for this lane the raw kernel column differs from the reference, so
+    # the equality above shows that batched_solve took the fallback (the
+    # rank-2 lane's raw column may match the installed LAPACK's pattern)
+    x_raw0, _ = lu_solve_pallas(jnp.moveaxis(W0, 0, -1), b.T, lane_tile=2)
+    assert not np.array_equal(np.asarray(x_raw0)[:, 0], ref0[0],
+                              equal_nan=True)
 
 
 def test_lu_auto_lane_tile_shares_vmem_formula():

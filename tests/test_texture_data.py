@@ -301,19 +301,40 @@ def test_resolve_auto_key_distinguishes_data(tmp_path):
     dec_data = resolve_auto(ep, spec, dt0=1e-2,
                             saveat=jnp.linspace(0.0, 5.0, 6),
                             cache_path=cache, repeats=1)
-    # the data-free twin computes its force in closed form: a callback that
-    # closes over the table array would hand the Pallas candidates a
-    # captured constant, which the kernel refuses (and a refused candidate
-    # fails the resolve instead of dropping out of it)
-    def free_rhs(u, p, t):
-        force = jnp.sin(1.3 * t) + 0.5 * jnp.cos(0.4 * t)
-        return jnp.stack([u[1], -p[0] * u[0] - p[1] * u[1] + force])
-
     free = EnsembleProblem(
-        dataclasses.replace(prob, f=free_rhs, data=None, name="free"),
+        dataclasses.replace(bind_problem_data(prob), name="free"),
         4, u0s=ep.materialize()[0], ps=ep.materialize()[1])
     dec_free = resolve_auto(free, spec, dt0=1e-2,
                             saveat=jnp.linspace(0.0, 5.0, 6),
                             cache_path=cache, repeats=1)
     assert dec_data.key != dec_free.key
     assert "data=" in dec_data.key
+    # the bound twin's RHS closes over the table arrays, which pallas_call
+    # refuses as captured constants: its Pallas candidates are pruned (not
+    # timed and not raised), while the data problem — tables passed as
+    # kernel inputs — keeps them
+    assert dec_data.source == dec_free.source == "tuned"
+    assert any("pallas" in label for label, _ in dec_data.timings)
+    assert not any("pallas" in label for label, _ in dec_free.timings)
+    assert dec_free.backend != "pallas"
+
+
+@pytest.mark.parametrize("mode", ["gather", "cubic"])
+def test_take1d_gathers_outside_kernels_masks_inside(mode):
+    """A 1-D lookup is an XLA gather (O(1) per lookup) on the vmap and XLA
+    paths, and a masked sum over the K nodes only while a Pallas kernel
+    body traces, where Mosaic has no 1-D gather.  Both give the same
+    values bitwise."""
+    from repro.core import interp
+    tab = UniformTable1D(
+        jnp.asarray(np.random.default_rng(0).normal(size=65)), 0.0, 0.1)
+    x = jnp.linspace(-1.0, 8.0, 37)
+
+    def prims():     # jnp.take nests its gather in a jit: print it all
+        return str(jax.make_jaxpr(lambda q: interp1d(tab, q, mode))(x))
+
+    outside, y_out = prims(), interp1d(tab, x, mode)
+    with interp.kernel_lookups():
+        inside, y_in = prims(), interp1d(tab, x, mode)
+    assert "gather" in outside and "gather" not in inside
+    np.testing.assert_array_equal(np.asarray(y_in), np.asarray(y_out))
