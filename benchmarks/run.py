@@ -1,13 +1,11 @@
 """Benchmark driver: one module per paper table/figure.
 
 ``PYTHONPATH=src python -m benchmarks.run [--only fig4,...]``
-Prints ``name,us_per_call,derived`` CSV rows per module, then the roofline
-summary table from the dry-run records (if present).
+Prints ``name,us_per_call,derived`` CSV rows per module.
 """
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 import traceback
@@ -64,29 +62,6 @@ def check_bench_imports(modname: str) -> None:
                     "a symbol that no longer exists (signature drift)")
 
 
-def print_roofline_summary():
-    for tag, results_dir in (("baseline", "results"),
-                             ("optimized", "results_optimized")):
-        path = os.path.join(results_dir, "roofline_all.json")
-        if not os.path.exists(path):
-            print(f"# (no {path} — run repro.launch.roofline)")
-            continue
-        with open(path) as f:
-            rows = json.load(f)
-        print(f"\n# ---- roofline summary [{tag}] "
-              "(single-pod; see EXPERIMENTS.md) ----")
-        print("arch,shape,bottleneck,t_compute_s,t_memory_s,t_collective_s,"
-              "useful_ratio,roofline_fraction")
-        for r in rows:
-            if "error" in r:
-                print(f"{r['arch']},{r['shape']},ERROR,,,,,")
-                continue
-            print(f"{r['arch']},{r['shape']},{r['bottleneck']},"
-                  f"{r['t_compute_s']:.4g},{r['t_memory_s']:.4g},"
-                  f"{r['t_collective_s']:.4g},{r['useful_ratio']:.3f},"
-                  f"{r['roofline_fraction']:.3f}")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--only", default="")
@@ -117,8 +92,6 @@ def main() -> None:
             failed.append(modname)
             print(f"# {modname} FAILED: {type(e).__name__}: {e}")
             traceback.print_exc()
-    if not args.dry:
-        print_roofline_summary()
     if failed:
         print(f"# {len(failed)} module(s) failed: {', '.join(failed)}")
     sys.exit(1 if failed else 0)

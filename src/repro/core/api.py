@@ -161,7 +161,9 @@ def solve_ensemble(eprob: EnsembleProblem, mesh: Optional[Mesh] = None,
                        out_specs=EnsembleResult(
                            ts=P(), us=spec, u_final=spec, t_final=spec,
                            naccept=count_spec, nreject=count_spec, nf=P(),
-                           status=P(), njac=P(), nfact=P()),
+                           status=P(), njac=P(), nfact=P(),
+                           steps_run=(None if shard_shapes.steps_run is None
+                                      else spec)),
                        check_vma=False)
     if kw.get("sensitivity") is not None:
         # the bounded adjoint loop wraps segments in jax.checkpoint, which

@@ -88,6 +88,11 @@ class EnsembleResult(NamedTuple):
     status: Array
     njac: Array = 0  # total Jacobian evaluations (stiff family; 0 elsewhere)
     nfact: Array = 0  # total W = I − γh·J factorizations (stiff family)
+    # (N,) int32: loop iterations the trajectory's lane tile ran, at least
+    # its naccept + nreject; lockstep occupancy is sum(naccept + nreject) /
+    # sum(steps_run), added on the host in int64.  None on paths without
+    # lane tiles (vmap, the erk array strategy, solve_kernel_fixed)
+    steps_run: Optional[Array] = None
 
 
 def _pad_to(x, n_target, axis=0):
@@ -135,7 +140,9 @@ def _untile(res, N, n):
         nreject=res.nreject.reshape(-1)[:N],
         nf=total(res.nf),
         status=jnp.max(res.status),
-        njac=total(res.njac), nfact=total(res.nfact))
+        njac=total(res.njac), nfact=total(res.nfact),
+        steps_run=jnp.broadcast_to(res.iters[:, None],
+                                   res.naccept.shape).reshape(-1)[:N])
 
 
 # ----------------------------------------------------------------------------
@@ -754,7 +761,7 @@ def _solve_sde(spec: MethodSpec, prob: SDEProblem, u0s, ps, *, ensemble,
                                    checkpoint_every=checkpoint_every)
         return _assemble_sde_result(ts, jnp.moveaxis(us, -1, 0), uf.T, N,
                                     n_steps, nf_per_step, t0, dt0, u0s.dtype,
-                                    estate)
+                                    estate, lane_tiled=True)
 
     if ensemble == "kernel" and backend == "pallas":
         from repro.kernels.em.ops import solve_sde_ensemble_kernel
@@ -832,7 +839,10 @@ def _solve_sde(spec: MethodSpec, prob: SDEProblem, u0s, ps, *, ensemble,
 
 
 def _assemble_sde_result(ts, us, uf, N, n_steps, nf_per_step, t0, dt,
-                         dtype, estate=None) -> EnsembleResult:
+                         dtype, estate=None,
+                         lane_tiled=False) -> EnsembleResult:
+    # lane_tiled: the XLA twin of the kernel, one fori loop of n_steps over
+    # every lane, reports that trip count as each lane's steps_run
     if estate is None:
         t_final = jnp.full((N,), t0 + n_steps * dt, dtype)
         naccept = jnp.full((N,), n_steps, jnp.int32)
@@ -845,7 +855,9 @@ def _assemble_sde_result(ts, us, uf, N, n_steps, nf_per_step, t0, dt,
         ts=ts, us=us, u_final=uf, t_final=t_final, naccept=naccept,
         nreject=jnp.zeros((N,), jnp.int32),
         nf=jnp.asarray(n_steps * nf_per_step * N),
-        status=jnp.asarray(0, jnp.int32))
+        status=jnp.asarray(0, jnp.int32),
+        steps_run=(jnp.full((N,), n_steps, jnp.int32) if lane_tiled
+                   else None))
 
 
 # ----------------------------------------------------------------------------

@@ -574,7 +574,8 @@ def solve_rosenbrock(f, rtab: RosenbrockTableau, u0, p, t0, tf, dt0, *,
                          jnp.where(out["done"], 0, 1)).astype(jnp.int32),
         nf=nsteps * nf_step,
         njac=out["njac"] if policy is not None else nsteps,
-        nfact=out["nfact"] if policy is not None else nsteps)
+        nfact=out["nfact"] if policy is not None else nsteps,
+        iters=out["iters"])
     if event is not None:
         return res, dict(event_t=out["event_t"], event_count=out["event_count"])
     return res

@@ -454,7 +454,8 @@ def sde_solve_fixed(prob: SDEProblem, u0, p, t0, dt, n_steps: int, key,
     return SolveResult(ts=ts, us=us, t_final=t_f, u_final=u_f,
                        naccept=jnp.asarray(n_steps), nreject=jnp.asarray(0),
                        status=jnp.asarray(0),
-                       nf=jnp.asarray(n_steps * (2 if method != "em" else 1)))
+                       nf=jnp.asarray(n_steps * (2 if method != "em" else 1)),
+                       iters=jnp.asarray(n_steps, jnp.int32))
 
 
 # ----------------------------------------------------------------------------
@@ -776,7 +777,7 @@ def sde_solve_adaptive(f, g, stepper, noise: str, u0, p, t0, tf, dt0, *,
         naccept=out["naccept"], nreject=out["nreject"],
         status=jnp.where(out["status"] > 0, out["status"],
                          jnp.where(out["done"], 0, 1)).astype(jnp.int32),
-        nf=out["nf"])
+        nf=out["nf"], iters=out["iters"])
     if event is not None:
         return res, dict(event_t=out["event_t"], event_count=out["event_count"])
     return res

@@ -48,6 +48,8 @@ class SolveResult(NamedTuple):
     nf: Array        # number of RHS evaluations (per control element)
     njac: Array = 0  # Jacobian evaluations (stiff family; 0 elsewhere)
     nfact: Array = 0  # W = I − γh·J factorizations (stiff family)
+    iters: Array = 0  # trip count of the integration loop: in lanes mode
+    #                   the tile's, at least every lane's naccept + nreject
 
 
 # ----------------------------------------------------------------------------
@@ -193,7 +195,8 @@ def solve_fixed(f, tab: Tableau, u0, p, t0, dt, n_steps: int,
     nf = jnp.asarray(n_steps * (tab.stages - (1 if tab.fsal else 0)) + (1 if tab.fsal else 0))
     return SolveResult(ts=ts, us=us, t_final=t_f, u_final=u_f,
                        naccept=jnp.asarray(n_steps), nreject=jnp.asarray(0),
-                       status=jnp.asarray(0), nf=nf)
+                       status=jnp.asarray(0), nf=nf,
+                       iters=jnp.asarray(n_steps, jnp.int32))
 
 
 # ----------------------------------------------------------------------------
@@ -448,7 +451,8 @@ def solve_adaptive(f, tab: Tableau, u0, p, t0, tf, dt0,
                       u_final=out["u"], naccept=out["naccept"],
                       nreject=out["nreject"], status=status,
                       nf=1 + _nf_per_attempt(tab, event)
-                      * (out["naccept"] + out["nreject"]))
+                      * (out["naccept"] + out["nreject"]),
+                      iters=out["iters"])
     if event is not None:
         return res, dict(event_t=out["event_t"], event_count=out["event_count"])
     return res

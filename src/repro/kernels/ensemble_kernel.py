@@ -14,11 +14,15 @@ VMEM-budget-aware `lane_tile` selection (§5.2's occupancy formula).  What
 varies per method family is only the *loop body*, supplied as a callback:
 
   body(ctx, u0 (n, B), p (m, B), extras) ->
-      (us (S, n, B), u_final (n, B), t_final (B,), stats (6, B) int32)
+      (us (S, n, B), u_final (n, B), t_final (B,), stats (7, B) int32)
 
-with stats rows (naccept, nreject, status, nf, njac, nfact) — the last two
-report the stiff family's Jacobian-evaluation and W-factorization work
-(zero for erk/sde).  Bodies for the three
+with stats rows (naccept, nreject, status, nf, njac, nfact, steps_run) —
+njac and nfact report the stiff family's Jacobian-evaluation and
+W-factorization work (zero for erk/sde); steps_run is the tile's loop trip
+count on every lane, the lane-steps the tile ran whether the lane was done
+or not.  The body function's name becomes the kernel's (`ensemble_erk`,
+`ensemble_rosenbrock`, `ensemble_sde`, `ensemble_sde_adaptive`), so a
+device trace finds each family's kernel by name.  Bodies for the three
 registered families (erk / rosenbrock / sde) are provided below; they reuse
 the shared numerical engines (`core.solvers`, `core.rosenbrock`, `core.sde`)
 unchanged — the paper's "automated translation": the same user RHS and the
@@ -188,6 +192,9 @@ class KernelContext(NamedTuple):
 #                 family bodies can peel `extras[-n_leaves:]` off the tail.
 Extra = Tuple[str, Array]
 
+# rows of the int32 stats block every body returns (module docstring)
+N_STATS = 7
+
 
 def run_ensemble_kernel(body: Callable, u0s: Array, ps: Array, *, ts: Array,
                         extras: Sequence[Extra] = (),
@@ -265,14 +272,14 @@ def run_ensemble_kernel(body: Callable, u0s: Array, ps: Array, *, ts: Array,
         jax.ShapeDtypeStruct((S, n, Np), dtype),      # us
         jax.ShapeDtypeStruct((n, Np), dtype),         # u_final
         jax.ShapeDtypeStruct((1, Np), dtype),         # t_final
-        # naccept / nreject / status / nf / njac / nfact
-        jax.ShapeDtypeStruct((6, Np), jnp.int32),
+        # naccept / nreject / status / nf / njac / nfact / steps_run
+        jax.ShapeDtypeStruct((N_STATS, Np), jnp.int32),
     ]
     out_specs = [
         pl.BlockSpec((S, n, B), lambda i: (0, 0, i)),
         pl.BlockSpec((n, B), lambda i: (0, i)),
         pl.BlockSpec((1, B), lambda i: (0, i)),
-        pl.BlockSpec((6, B), lambda i: (0, i)),
+        pl.BlockSpec((N_STATS, B), lambda i: (0, i)),
     ]
 
     n_in = len(args)
@@ -294,14 +301,15 @@ def run_ensemble_kernel(body: Callable, u0s: Array, ps: Array, *, ts: Array,
 
     fn = pl.pallas_call(kernel, grid=(T,), in_specs=in_specs,
                         out_specs=out_specs, out_shape=out_shape,
-                        interpret=interpret)
+                        interpret=interpret, name=body.__name__)
     us, uf, t_fin, stats = fn(*args)
     return EnsembleResult(
         ts=jnp.asarray(ts, dtype), us=lanes_to_traj(us, N),
         u_final=uf.T[:N], t_final=t_fin[0, :N],
         naccept=stats[0, :N], nreject=stats[1, :N],
         nf=jnp.sum(stats[3, :N]), status=jnp.max(stats[2, :N]),
-        njac=jnp.sum(stats[4, :N]), nfact=jnp.sum(stats[5, :N]))
+        njac=jnp.sum(stats[4, :N]), nfact=jnp.sum(stats[5, :N]),
+        steps_run=stats[6, :N])
 
 
 def kernel_adjoint(primal_fn: Callable, replay_fn: Callable) -> Callable:
@@ -437,6 +445,7 @@ def run_ensemble_kernel_staged(body_factory: Callable, u0s: Array, ps: Array,
                 nreject=acc.nreject + res.nreject,
                 nf=acc.nf + res.nf, njac=acc.njac + res.njac,
                 nfact=acc.nfact + res.nfact,
+                steps_run=acc.steps_run + res.steps_run,
                 status=jnp.maximum(acc.status, res.status))
     return acc._replace(ts=jnp.asarray(ts_np, u0s.dtype),
                         us=jnp.concatenate(parts, axis=1))
@@ -471,6 +480,11 @@ def _data_binder(data):
     return rebind
 
 
+def _per_lane(iters, like):
+    """The tile's loop trip count (an int32 scalar) on every lane."""
+    return jnp.broadcast_to(iters, like.shape).astype(jnp.int32)
+
+
 def erk_body(f, tab, *, t0: float, tf: float, dt0: float, rtol: float,
              atol: float, adaptive: bool, max_iters: int, event=None,
              data=None):
@@ -480,7 +494,7 @@ def erk_body(f, tab, *, t0: float, tf: float, dt0: float, rtol: float,
 
     rebind = _data_binder(data)
 
-    def body(ctx, u0, p, extras):
+    def ensemble_erk(ctx, u0, p, extras):
         fb = f
         if rebind is not None:
             extras, d = rebind(extras)
@@ -495,10 +509,10 @@ def erk_body(f, tab, *, t0: float, tf: float, dt0: float, rtol: float,
         zero = jnp.zeros_like(res.naccept)
         stats = jnp.stack([res.naccept, res.nreject,
                            res.status * jnp.ones_like(res.naccept), res.nf,
-                           zero, zero])
+                           zero, zero, _per_lane(res.iters, res.naccept)])
         return res.us, res.u_final, res.t_final, stats
 
-    return body
+    return ensemble_erk
 
 
 def rosenbrock_body(f, rtab, *, jac=None, t0: float, tf: float, dt0: float,
@@ -520,7 +534,7 @@ def rosenbrock_body(f, rtab, *, jac=None, t0: float, tf: float, dt0: float,
 
     rebind = _data_binder(data)
 
-    def body(ctx, u0, p, extras):
+    def ensemble_rosenbrock(ctx, u0, p, extras):
         fb, jb = f, jac
         if rebind is not None:
             extras, d = rebind(extras)
@@ -537,10 +551,11 @@ def rosenbrock_body(f, rtab, *, jac=None, t0: float, tf: float, dt0: float,
             res, _ = res
         stats = jnp.stack([res.naccept, res.nreject, res.status, res.nf,
                            jnp.broadcast_to(res.njac, res.naccept.shape),
-                           jnp.broadcast_to(res.nfact, res.naccept.shape)])
+                           jnp.broadcast_to(res.nfact, res.naccept.shape),
+                           _per_lane(res.iters, res.naccept)])
         return res.us, res.u_final, res.t_final, stats
 
-    return body
+    return ensemble_rosenbrock
 
 
 def sde_body(f, g, stepper, noise: str, *, t0: float, dt: float,
@@ -562,7 +577,7 @@ def sde_body(f, g, stepper, noise: str, *, t0: float, dt: float,
     S = n_steps // save_every
     rebind = _data_binder(data)
 
-    def body(ctx, u0, p, extras):
+    def ensemble_sde(ctx, u0, p, extras):
         f_, g_ = f, g
         if rebind is not None:
             extras, d = rebind(extras)
@@ -607,10 +622,11 @@ def sde_body(f, g, stepper, noise: str, *, t0: float, dt: float,
             t_final = estate["t_out"].astype(dtype)
             naccept = estate["naccept"]
         stats = jnp.stack([naccept, i32(0), i32(0),
-                           i32(n_steps * nf_per_step), i32(0), i32(0)])
+                           i32(n_steps * nf_per_step), i32(0), i32(0),
+                           i32(n_steps)])
         return us, u_f, t_final, stats
 
-    return body
+    return ensemble_sde
 
 
 def sde_adaptive_body(f, g, stepper, noise: str, *, t0: float, tf: float,
@@ -630,7 +646,7 @@ def sde_adaptive_body(f, g, stepper, noise: str, *, t0: float, tf: float,
 
     rebind = _data_binder(data)
 
-    def body(ctx, u0, p, extras):
+    def ensemble_sde_adaptive(ctx, u0, p, extras):
         f_, g_ = f, g
         if rebind is not None:
             extras, d = rebind(extras)
@@ -654,7 +670,7 @@ def sde_adaptive_body(f, g, stepper, noise: str, *, t0: float, tf: float,
         zero = jnp.zeros_like(res.naccept)
         stats = jnp.stack([res.naccept, res.nreject,
                            res.status * jnp.ones_like(res.naccept), res.nf,
-                           zero, zero])
+                           zero, zero, _per_lane(res.iters, res.naccept)])
         return res.us, res.u_final, res.t_final, stats
 
-    return body
+    return ensemble_sde_adaptive

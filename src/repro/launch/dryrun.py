@@ -34,7 +34,7 @@ from repro.train.serve import make_serve_plan
 from repro.train.trainer import make_train_step, pick_accum
 
 # --------------------------------------------------------------------------
-# HLO collective accounting (roofline input; see launch/roofline.py)
+# HLO collective accounting
 # --------------------------------------------------------------------------
 
 _COLL_RE = re.compile(
@@ -95,22 +95,15 @@ def analyze(lowered, compiled):
 
 def run_cell(arch: str, shape_name: str, multi_pod: bool,
              fsdp: bool = True, extra_tag: str = "",
-             scan_unroll: bool = False, shard_mode: str = None,
-             remat_mode="full") -> dict:
-    """Lower + compile one cell.
-
-    scan_unroll=True is the roofline-calibration mode: layer scans are fully
-    unrolled (XLA cost analysis counts a rolled scan body only once) and
-    gradient accumulation is forced to 1 (its scan would hide flops the same
-    way). Used ONLY with shallow depth overrides (launch/roofline.py).
-    """
+             shard_mode: str = None, remat_mode="full") -> dict:
+    """Lower + compile one cell."""
     cfg = get_arch(arch)
     shape = SHAPES[shape_name]
     mesh = make_production_mesh(multi_pod=multi_pod)
     rec = {"arch": arch, "shape": shape_name,
            "mesh": "multi" if multi_pod else "single",
            "tag": extra_tag, "ok": False}
-    unroll = True if scan_unroll else 1
+    unroll = 1
     remat = "dots" if remat_mode == "dots" else True
     t0 = time.time()
     try:
@@ -119,8 +112,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
                                 unroll=unroll)
             nd = mesh.devices.size // mesh.shape["model"]
             per_dev = shape.global_batch // nd
-            accum = 1 if scan_unroll else pick_accum(cfg, per_dev,
-                                                     shape.seq_len)
+            accum = pick_accum(cfg, per_dev, shape.seq_len)
             rec["accum"] = accum
             opt = AdamW(lr=cosine_schedule(3e-4, 100, 10_000))
             batch = train_batch_specs(cfg, shape)

@@ -115,8 +115,8 @@ class DecoderLM:
         # dispatch buffers at 32k prefill are E/topk-times over-provisioned
         # (grok: 8/2 = 4x, measured 52 GiB/device)
         self.moe_inference_cf = None
-        # unroll=True: unroll layer scans (roofline analysis mode — XLA cost
-        # analysis counts a rolled scan body only ONCE; see launch/roofline)
+        # unroll=True: unroll layer scans (XLA cost analysis counts a rolled
+        # scan body only ONCE)
         self.unroll = unroll
         self.act_shard = ActivationSharding(None)
         # q_chunk>0: memory-efficient attention over query blocks (set by the

@@ -8,7 +8,8 @@ described (not attached) v5e: the erk (fixed and adaptive), sde and
 rosenbrock bodies of `run_ensemble_kernel`, the batched LU kernel, and a
 data-driven erk solve whose table leaves ride the "table" extras.  Nothing
 runs, so they check only that Mosaic accepts each kernel, that it stays
-inside the v5e's scoped VMEM limit and that the program holds a TPU kernel.
+inside the v5e's scoped VMEM limit and that the program holds a TPU kernel
+(named for its family, with the seven-row stats block among its outputs).
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU compiler library, and xdist workers all import
@@ -75,6 +76,20 @@ def compile_v5e(one_chip):
     compilation_cache.reset_cache()
 
 
+def with_steps(res, field):
+    """One output of the solve and its steps_run, so neither is dropped."""
+    return getattr(res, field), res.steps_run
+
+
+def assert_family_kernel(compiled, name):
+    """The program's Mosaic call carries the family's name and returns the
+    (7, B) int32 stats block, steps_run its last row."""
+    calls = [ln.strip() for ln in compiled.as_text().splitlines()
+             if "tpu_custom_call" in ln]
+    assert any(ln.startswith(f"%{name}.") and "s32[7," in ln
+               for ln in calls), calls
+
+
 @pytest.mark.parametrize("adaptive,N,S", [(False, 2 ** 22, 4),
                                           (True, 2 ** 20, 5)])
 def test_erk_body_compiles(compile_v5e, adaptive, N, S):
@@ -82,10 +97,11 @@ def test_erk_body_compiles(compile_v5e, adaptive, N, S):
     ts = jnp.linspace(1.0 / S, 1.0, S, dtype=F32)
     body = erk_body(lorenz_rhs, tab, t0=0.0, tf=1.0, dt0=1e-3, rtol=1e-5,
                     atol=1e-5, adaptive=adaptive, max_iters=100_000)
-    compile_v5e(lambda u, p: run_ensemble_kernel(
+    compiled = compile_v5e(lambda u, p: with_steps(run_ensemble_kernel(
         body, u, p, ts=ts, extras=[("broadcast", ts)],
-        work_words=erk_work_words(3, 3, tab.stages), interpret=False).us,
+        work_words=erk_work_words(3, 3, tab.stages), interpret=False), "us"),
         ((N, 3), F32), ((N, 3), F32))
+    assert_family_kernel(compiled, "ensemble_erk")
 
 
 def test_sde_body_compiles(compile_v5e):
@@ -95,10 +111,11 @@ def test_sde_body_compiles(compile_v5e):
                     save_every=n_steps, m_noise=3, seed=0, use_table=False)
     ts = sde_save_grid(0.0, 1.0 / n_steps, n_steps, n_steps, F32)
     off = jnp.asarray([0], jnp.uint32)
-    compile_v5e(lambda u, p: run_ensemble_kernel(
+    compiled = compile_v5e(lambda u, p: with_steps(run_ensemble_kernel(
         body, u, p, ts=ts, extras=[("broadcast", off)],
-        work_words=sde_work_words(3, 2, 3), interpret=False).u_final,
+        work_words=sde_work_words(3, 2, 3), interpret=False), "u_final"),
         ((N, 3), F32), ((N, 2), F32))
+    assert_family_kernel(compiled, "ensemble_sde")
 
 
 def test_rosenbrock_body_compiles(compile_v5e):
@@ -108,12 +125,13 @@ def test_rosenbrock_body_compiles(compile_v5e):
     body = rosenbrock_body(vdp_rhs, spec.rtableau, t0=0.0, tf=1.0, dt0=1e-2,
                            rtol=1e-5, atol=1e-5, max_iters=100_000,
                            w_reuse=spec.w_reuse)
-    compile_v5e(lambda u, p: run_ensemble_kernel(
+    compiled = compile_v5e(lambda u, p: with_steps(run_ensemble_kernel(
         body, u, p, ts=ts, extras=[("broadcast", ts)],
         work_words=rosenbrock_work_words(2, 1, stages=spec.rtableau.stages,
                                          w_reuse=bool(spec.w_reuse)),
-        interpret=False).u_final,
+        interpret=False), "u_final"),
         ((N, 2), F32), ((N, 1), F32))
+    assert_family_kernel(compiled, "ensemble_rosenbrock")
 
 
 def test_lu_kernel_compiles(compile_v5e):
@@ -132,10 +150,11 @@ def test_table_extras_compile(compile_v5e):
     body = erk_body(prob.f, tab, t0=0.0, tf=5.0, dt0=1e-2, rtol=1e-5,
                     atol=1e-5, adaptive=True, max_iters=100_000,
                     data=prob.data)
-    compile_v5e(lambda u, p, *lv: run_ensemble_kernel(
+    compiled = compile_v5e(lambda u, p, *lv: with_steps(run_ensemble_kernel(
         body, u, p, ts=ts,
         extras=[("broadcast", ts)] + [("table", leaf) for leaf in lv],
         work_words=erk_work_words(2, 2, tab.stages),
-        fixed_words=data_words(prob.data), interpret=False).u_final,
+        fixed_words=data_words(prob.data), interpret=False), "u_final"),
         ((N, 2), F32), ((N, 2), F32), *[(leaf.shape, leaf.dtype)
                                          for leaf in leaves])
+    assert_family_kernel(compiled, "ensemble_erk")
