@@ -199,8 +199,8 @@ def check(out, inputs, traffic, seed):
 
 def work(traffic):
     """Algorithmic work per lane-step and HBM bytes per path (state and
-    parameters read once; saves, final state, time and six stats words
+    parameters read once; saves, final state, time and seven stats words
     written once)."""
     s = traffic["n_steps"] // traffic["save_every"]
     return dict(ops_per_attempt=OPS_EM_STEP, ops_per_save=0, saves=s,
-                bytes_per_traj=4 * (3 + 2 + 3 * s + 3 + 1 + 6))
+                bytes_per_traj=4 * (3 + 2 + 3 * s + 3 + 1 + 7))

@@ -291,7 +291,7 @@ def check(out, inputs, traffic, seed):
 def work(traffic):
     """Algorithmic work, counted per useful lane-step (bench/workcount.py)
     and bytes of HBM traffic per trajectory (state and parameters read
-    once, saves, final state and time and six stats words written once)."""
+    once, saves, final state and time and seven stats words written once)."""
     s = len(save_times(traffic))
     if traffic["adaptive"]:
         per_attempt, per_save = OPS_ADAPTIVE_ATTEMPT, OPS_DENSE_SAVE
@@ -299,4 +299,4 @@ def work(traffic):
         # saves land on the step grid: the save is the step's own state
         per_attempt, per_save = OPS_FIXED_STEP, 0
     return dict(ops_per_attempt=per_attempt, ops_per_save=per_save,
-                saves=s, bytes_per_traj=4 * (3 + 3 + 3 * s + 3 + 1 + 6))
+                saves=s, bytes_per_traj=4 * (3 + 3 + 3 * s + 3 + 1 + 7))

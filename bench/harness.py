@@ -10,15 +10,21 @@ A run:
 
 1. Set-up (``setup_s``, from process start): imports, device start, the
    persistent compilation cache, inputs made on the device from the seed,
-   the solve compiled (its TPU kernel checked) and run once untimed.
-2. Window: solves back to back, each ending in ``block_until_ready``,
-   until one ends after ``seconds``.  Compilations inside are counted.
+   the solve compiled (its TPU kernel checked) and run once untimed;
+   last, the objects set-up left are collected and frozen, so that no
+   collection inside the window walks them.
+2. Window (`time_window`): solves two deep, each dispatched before the
+   one ahead of it is waited on, so one always stands queued behind the
+   running one; each is counted once its ``block_until_ready`` returns.
+   Once one ends after ``seconds`` nothing more is dispatched, and the
+   window ends with the queued one.  Compilations inside are counted.
 3. Checks, after the window and the memory reading, on the last solve.
 4. With ``trace``: the window runs under the profiler, the vector peak is
    measured, and the per-layer readers take their numbers from the trace.
 """
 from __future__ import annotations
 
+import gc
 import importlib
 import json
 import os
@@ -172,6 +178,30 @@ def check(c: SimpleNamespace, out, inputs, seed: int) -> list:
             for name, value in c.cfg.check(out, inputs, c.traffic, seed)]
 
 
+def time_window(dispatch, wait, seconds: float, clock=time.perf_counter):
+    """Run solves two deep for at least `seconds`.
+
+    `dispatch()` enqueues a solve and returns its output; `wait(out)`
+    returns once that solve has ended.  Solve k+1 is dispatched before
+    solve k is waited on, so the device runs on through the host's sync
+    after each solve, and never more than one solve waits in the queue.
+    Once a solve ends after `seconds`, the one still queued is waited on
+    and counted, and the window ends with it: every solve dispatched is
+    counted.  Returns (last output, solves, t_start, t_end)."""
+    t_start = clock()
+    running = dispatch()
+    solves = 0
+    while True:
+        queued = dispatch()
+        wait(running)
+        solves += 1
+        running = queued
+        if clock() - t_start >= seconds:
+            break
+    wait(running)
+    return running, solves + 1, t_start, clock()
+
+
 def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              t_process: float, rehearse_n: int = None, fault=None) -> dict:
     """Run one cell and return its result object (see `bench/run.py`).
@@ -204,13 +234,17 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         raise RuntimeError("the timed program holds no Mosaic kernel "
                            "(tpu_custom_call): the Pallas path did not "
                            "compile for the chip")
-    out = jax.block_until_ready(compiled(*inputs))         # warm-up
+    jax.block_until_ready(compiled(*inputs))    # warm-up, not kept
     marks.append(("warm-up", time.perf_counter()))
     peak = None
     if trace and on_tpu:
         from bench.peaks import lookup, measure_vector_peak
         peak = dict(lookup(devices[0].device_kind),
                     measured=measure_vector_peak())
+    # set-up's objects collected and frozen: no collection in the window
+    # walks them again
+    gc.collect()
+    gc.freeze()
     setup_s = time.perf_counter() - t_process
     log("# setup: " + ", ".join(
         f"{name} {b - a:.3f} s" for (_, a), (name, b)
@@ -225,15 +259,8 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             opts.python_tracer_level = 0
             jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
         with jax.profiler.TraceAnnotation("bench_window"):
-            t_start = time.perf_counter()
-            solves = 0
-            while True:
-                out = compiled(*inputs)
-                jax.block_until_ready(out)
-                solves += 1
-                t_end = time.perf_counter()
-                if t_end - t_start >= seconds:
-                    break
+            out, solves, t_start, t_end = time_window(
+                lambda: compiled(*inputs), jax.block_until_ready, seconds)
         if trace:
             jax.profiler.stop_trace()
     window_s = t_end - t_start
@@ -285,6 +312,7 @@ def read_trace(spec, workload, trace_dir, cfg, traffic, out, n, chips,
     trace = tr.load(path)
     span = tr.find_span(trace, "bench_window")
     stats = tr.reduce(trace, span, f"jit_{SOLVE_NAME}")
+    tr.check_solves(stats, solves)
     work = cfg.work(traffic)
     attempts = (np.asarray(jax.device_get(out["naccept"]), np.int64)
                 + np.asarray(jax.device_get(out["nreject"]), np.int64))

@@ -4,8 +4,9 @@
 
 Cells, metrics and bounds are in `BENCHMARK.json` at the checkout root.
 The last line of standard output is one JSON object: ``correct``,
-``attempted`` (solves in the window), ``failed`` (solves whose output the
-checks refused), ``metrics`` (the cell's end-to-end metrics, or with
+``attempted`` (solves in the window: every solve dispatched there,
+counted once it ended), ``failed`` (solves whose output the checks
+refused), ``metrics`` (the cell's end-to-end metrics, or with
 ``--trace 1`` its per-layer metrics), ``device``, with ``--trace 1`` the
 ``breakdown``, and last ``checks``: each compared number with its limit.
 The same numbers end standard error.

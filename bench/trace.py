@@ -113,10 +113,12 @@ def reduce_device(plane: str, lines: Dict[str, List[Event]],
     ops = [e for e in lines.get("XLA Ops", []) if e.end > lo and e.start < hi]
     busy = merge(clip([(e.start, e.end) for e in ops], lo, hi))
     # a solve program belongs to the window where it overlaps it: the
-    # host's and the device's clocks agree only to some microseconds
-    solves = merge([(e.start, e.end) for e in lines.get("XLA Modules", [])
-                    if e.name.startswith(module_prefix)
-                    and e.end > lo and e.start < hi])
+    # host's and the device's clocks agree only to some microseconds.
+    # Each execution counts once, also where two touch back to back.
+    runs = [(e.start, e.end) for e in lines.get("XLA Modules", [])
+            if e.name.startswith(module_prefix)
+            and e.end > lo and e.start < hi]
+    solves = merge(runs)
     mosaic = layout = 0.0
     op_ns: Dict[str, float] = {}
     for e in ops:
@@ -130,7 +132,7 @@ def reduce_device(plane: str, lines: Dict[str, List[Event]],
     gaps = [(a[1], b[0]) for a, b in zip([(lo, lo)] + busy, busy + [(hi, hi)])
             if b[0] > a[1]]
     return DeviceStats(device=plane, busy_ns=length(busy), mosaic_ns=mosaic,
-                       layout_ns=layout, solves=len(solves), op_ns=op_ns,
+                       layout_ns=layout, solves=len(runs), op_ns=op_ns,
                        gaps=gaps)
 
 
@@ -138,6 +140,16 @@ def reduce(trace: Trace, window: Interval,
            module_prefix: str) -> List[DeviceStats]:
     return [reduce_device(p, lines, window, module_prefix)
             for p, lines in trace.devices.items()]
+
+
+def check_solves(stats: List[DeviceStats], solves: int) -> None:
+    """Every device ran the solve program as often as the host counted
+    solves in the window; the per-solve readers divide by the host's count."""
+    for d in stats:
+        if d.solves != solves:
+            raise RuntimeError(
+                f"{d.device} ran the solve program {d.solves} times in the "
+                f"traced window; the host counted {solves} solves")
 
 
 def name_gap(trace: Trace, gap: Interval, window: str = "bench_window") -> str:

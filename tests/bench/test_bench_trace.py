@@ -64,9 +64,34 @@ def test_reduce_by_hand():
     assert tr.name_gap(t, (0.0, 11.0)) == "outer"      # not the window
 
 
+def test_solves_back_to_back_count_apart():
+    # two executions that touch on the device clock are two solves, and
+    # the second one's copy is still the lane layout's
+    lines = {"XLA Modules": [_ev(10, 40, "jit_bench_solve(1)"),
+                             _ev(40, 70, "jit_bench_solve(1)")],
+             "XLA Ops": [_ev(10, 35, KERNEL), _ev(35, 40, COPY),
+                         _ev(40, 65, KERNEL), _ev(65, 70, COPY)]}
+    t = tr.Trace(devices={"/device:TPU:0": lines}, host=[])
+    (d,) = tr.reduce(t, (0.0, 100.0), "jit_bench_solve")
+    assert d.solves == 2
+    assert d.layout_ns == 10.0 and d.mosaic_ns == 50.0
+    r = _reading([d], 100.0, attempts=[1000], lanes=[4])
+    assert kernel_ms_per_solve.read(r) == pytest.approx(25e-6)
+    tr.check_solves([d], 2)
+
+
+def test_trace_and_host_counts_must_agree():
+    (d,) = tr.reduce(_hand_trace(), (0.0, 100.0), "jit_bench_solve")
+    tr.check_solves([d], 2)
+    for host_count in (1, 3):
+        with pytest.raises(RuntimeError, match="host counted"):
+            tr.check_solves([d], host_count)
+
+
 def _reading(stats, window_ns, attempts, lanes, peak=1e9):
     return SimpleNamespace(
-        devices=stats, window_ns=window_ns, attempts=attempts, lanes=lanes,
+        devices=stats, window_ns=window_ns, solves=stats[0].solves,
+        attempts=attempts, lanes=lanes,
         work=dict(ops_per_attempt=10, ops_per_save=3, saves=2),
         peak={"vector_ops_per_s": peak}, nf=0, n=sum(lanes))
 

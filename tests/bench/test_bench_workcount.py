@@ -4,9 +4,10 @@ beside them from the equations and the tableau (bench/workcount.py)."""
 import jax.numpy as jnp
 import pytest
 
-from bench import peaks
+from bench import harness, peaks
 from bench.configs import gbm_mc, lorenz_sweep
 from bench.workcount import count_ops
+from repro.kernels.ensemble_kernel import N_STATS
 
 F32 = jnp.float32
 U = jnp.asarray([1.0, 0.5, 2.0], F32)
@@ -74,3 +75,16 @@ def test_work_uses_the_stepping_of_the_traffic():
 def test_peak_microkernel_count():
     # x = x * a + b on every element of an (8, 128) tile: 2 operations
     assert peaks.ops(chains=16, unroll=4, iters=10) == 16 * 4 * 10 * 2 * 1024
+
+
+@pytest.mark.parametrize("config, traffic, fixed_words", [
+    (lorenz_sweep, "fixed_1000_steps", 3 + 3 + 3 + 1),
+    (lorenz_sweep, "adaptive_tol_1e-5", 3 + 3 + 3 + 1),
+    (gbm_mc, "em_200_steps", 3 + 2 + 3 + 1),
+])
+def test_bytes_count_every_stats_row_the_kernel_writes(config, traffic,
+                                                       fixed_words):
+    # state and parameters read; final state and time, each save's state
+    # and every row of the kernel's int32 stats block written
+    w = config.work(harness.load_traffic(traffic))
+    assert w["bytes_per_traj"] == 4 * (fixed_words + 3 * w["saves"] + N_STATS)
